@@ -20,9 +20,9 @@ The fleet rows compare three paths:
 
 On top (EXPERIMENTS.md §Scheduler-Perf): ``selection_bench`` times the
 schedulers' fused masked selection against the seed three-pass
-helpers, and ``phase_breakdown`` attributes one event's wall clock to
-phase-1 / scheduler / apply-decision / next-event+integrate; both are
-recorded into BENCH_fleet.json by ``benchmarks.run``.
+helpers; it is recorded into BENCH_fleet.json by ``benchmarks.run``.
+Device time per engine phase comes from a profiler trace of the fused
+loop, split by ``engine.scope_map`` (docs/observability.md).
 """
 from __future__ import annotations
 
@@ -42,9 +42,7 @@ from repro.core.scheduler import (
 )
 from repro.core.state import broadcast_lanes, init_state
 from repro.core.sweep import make_workload_batch
-from repro.kernels.dispatch import resolved_impl
 from repro.kernels.sched_select import masked_lex_argmin
-from repro.kernels.sim_tick import fleet_tick
 
 
 def _time(fn, reps=3):
@@ -527,118 +525,6 @@ def apply_bench(n_rounds: int = 24, reps: int = 7) -> dict:
     # sanity: both commit paths agree before we publish a speedup
     assert bool(jnp.array_equal(legacy(), fused()))
     return out
-
-
-def phase_breakdown(n_events: int = 150) -> dict:
-    """Per-phase cost attribution on the 64-lane skewed batch.
-
-    Steps the lane-major loop body event by event from the host, with
-    each phase jitted separately and synchronised, so the wall clock of
-    one event splits into phase-1 (fused sim_tick + its application),
-    scheduler, apply-decision, and next-event + utilisation
-    integration. The per-phase *shares* are the signal (host sync adds
-    a constant per phase); absolute engine throughput lives in the
-    fleet rows. Finished lanes are not masked out here — attribution
-    only, not a semantics path.
-    """
-    params = _fleet_params(smoke=False)
-    scheduler_fn = get_vector_scheduler("priority", early_exit=True)
-    ss0 = get_vector_scheduler_init("priority")(params)
-    F = 64
-    wls = make_workload_batch(params, list(range(F)))
-    horizon = jnp.int32(params.horizon_ticks)
-    arr_sorted = engine_mod._sorted_arrivals(wls.arrival)
-    states = broadcast_lanes(init_state(params), F)
-    scheds = broadcast_lanes(ss0, F)
-
-    @jax.jit
-    def f_phase1(states, wls):
-        ph = fleet_tick(
-            states.ctr_status, states.ctr_end, states.ctr_oom,
-            states.ctr_cpus, states.ctr_ram, states.ctr_pool,
-            states.pipe_status, wls.arrival, states.pipe_release,
-            states.tick, num_pools=params.num_pools,
-        )
-        return jax.vmap(
-            lambda s, w, t, p: executor.apply_fused_phase1(s, w, t, params, p)
-        )(states, wls, states.tick, ph)
-
-    @jax.jit
-    def f_sched(scheds, states, wls):
-        return jax.vmap(
-            lambda ss, s, w: scheduler_fn(ss, s, w, params)
-        )(scheds, states, wls)
-
-    @jax.jit
-    def f_apply(states, wls, decs):
-        return jax.vmap(
-            lambda s, w, d, t: executor.apply_decision(
-                s, w, d, t, params, early_exit=True
-            )
-        )(states, wls, decs, states.tick)
-
-    @jax.jit
-    def f_advance(states, wls, arr_sorted, decs):
-        def one(state, wl, arr, dec):
-            tick = state.tick
-            acted = (
-                jnp.any(dec.suspend)
-                | jnp.any(dec.reject)
-                | jnp.any(dec.assign_pipe >= 0)
-            )
-            nxt, cursor = engine_mod._next_event_registers(
-                state, arr, tick, acted
-            )
-            nxt = jnp.minimum(nxt, horizon)
-            state = executor.integrate(
-                state, tick, nxt, params, exact_buckets=True
-            )
-            return state._replace(tick=nxt, nxt_arrival_cursor=cursor)
-
-        return jax.vmap(one)(states, wls, arr_sorted, decs)
-
-    # compile everything once off the clock
-    s1 = f_phase1(states, wls)
-    sc, decs = f_sched(scheds, s1, wls)
-    s2 = f_apply(s1, wls, decs)
-    jax.block_until_ready(f_advance(s2, wls, arr_sorted, decs))
-
-    acc = {"phase1": 0.0, "scheduler": 0.0, "apply": 0.0, "advance": 0.0}
-    for _ in range(n_events):
-        t0 = time.perf_counter()
-        states = jax.block_until_ready(f_phase1(states, wls))
-        t1 = time.perf_counter()
-        scheds, decs = jax.block_until_ready(f_sched(scheds, states, wls))
-        t2 = time.perf_counter()
-        states = jax.block_until_ready(f_apply(states, wls, decs))
-        t3 = time.perf_counter()
-        states = jax.block_until_ready(
-            f_advance(states, wls, arr_sorted, decs)
-        )
-        t4 = time.perf_counter()
-        acc["phase1"] += t1 - t0
-        acc["scheduler"] += t2 - t1
-        acc["apply"] += t3 - t2
-        acc["advance"] += t4 - t3
-    total = sum(acc.values())
-    return {
-        "n_events": n_events,
-        "us_per_event": {
-            k: round(v * 1e6 / n_events, 1) for k, v in acc.items()
-        },
-        "share": {k: round(v / total, 3) for k, v in acc.items()},
-        # what each fused kernel resolved to on THIS backend, with the
-        # batching each call site actually uses: fleet_tick sees the
-        # explicit [F, ...] batch; the state_update and sched_select
-        # landings run per-lane under the engine's vmap (ref by design
-        # — see docs/architecture.md §"Kernel subsystems")
-        "impl": {
-            "sim_tick.fleet_tick": resolved_impl(batched=True),
-            "state_update.retire_land": resolved_impl(batched=False),
-            "state_update.assign_gather": resolved_impl(batched=False),
-            "sched_select.masked_lex_argmin": resolved_impl(batched=False),
-        },
-    }
 
 
 def main(print_rows: bool = True, smoke: bool = False) -> list[dict]:

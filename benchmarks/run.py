@@ -41,7 +41,6 @@ SMOKE_PERFETTO = pathlib.Path("BENCH_trace.perfetto.json")
 def write_fleet_json(
     rows: list[dict],
     smoke: bool,
-    phase_breakdown: dict | None = None,
     scenario_rows: list[dict] | None = None,
     search_rows: list[dict] | None = None,
     search_history: dict | None = None,
@@ -54,8 +53,7 @@ def write_fleet_json(
     the same core shard_mapped over every local device (event-density
     lane binning on). The ``selection`` row is the scheduler-selection
     microbench (three-pass helpers vs the fused ``sched_select``
-    kernel), and ``phase_breakdown`` the per-event phase shares —
-    both feed EXPERIMENTS.md §Scheduler-Perf. ``scenario_rows``
+    kernel), which feeds EXPERIMENTS.md §Scheduler-Perf. ``scenario_rows``
     (``engine_throughput.scenario_fleet_bench``) track fused/sharded
     throughput per scenario family — realistic-skew numbers for future
     binning/engine PRs, not just seed-batch variance. ``search_rows``
@@ -99,8 +97,6 @@ def write_fleet_json(
         payload["closed_loop_overhead_pct"] = closed.get(
             "closed_loop_overhead_pct"
         )
-    if phase_breakdown is not None:
-        payload["phase_breakdown"] = phase_breakdown
     if scenario_rows is not None:
         payload["scenario_rows"] = scenario_rows
     if search_rows is not None:
@@ -245,10 +241,11 @@ def check_smoke_regression(loaded: dict, baseline: dict | None) -> bool | None:
 
 
 def _maybe_profile(trace_dir: str | None):
-    """Opt-in ``jax.profiler.trace`` around the benchmark body; the
-    engine's ``jax.named_scope`` phase annotations (phase1 / scheduler /
-    apply / advance / telemetry) label the resulting timeline. View the
-    output in TensorBoard or https://ui.perfetto.dev."""
+    """Opt-in ``jax.profiler.trace`` around the benchmark body, viewable
+    in TensorBoard or https://ui.perfetto.dev. The program's host spans
+    (``eudoxia/*``) label the host timeline; a device op carries only
+    its HLO instruction's name, which ``engine.scope_map`` maps to the
+    engine phase (docs/observability.md, "Profiling the program")."""
     if not trace_dir:
         return contextlib.nullcontext()
     import jax
@@ -438,9 +435,9 @@ def main() -> None:
     ap.add_argument("--no-regression-gate", action="store_true",
                     help="skip the --smoke fused-throughput regression gate")
     ap.add_argument("--profile", metavar="DIR", default=None,
-                    help="wrap the benchmark body in jax.profiler.trace(DIR); "
-                         "the engine's named_scope phase annotations label "
-                         "the timeline (view in Perfetto / TensorBoard)")
+                    help="wrap the benchmark body in jax.profiler.trace(DIR) "
+                         "(view in Perfetto / TensorBoard); map device ops "
+                         "to engine phases with repro.core.engine.scope_map")
     ap.add_argument("--record-smoke-baseline", action="store_true",
                     help="with --smoke: run the smoke bench three times and "
                          "record the LOWEST fused/vmap ratio as the committed "
@@ -645,16 +642,12 @@ def main() -> None:
                 r["wall_s"] * 1e6,
                 f"ticks/s={r['ticks_per_s']}",
             )
-        breakdown = engine_throughput.phase_breakdown()
-        print("phase breakdown (us/event):", breakdown["us_per_event"])
-        print("phase shares:", breakdown["share"])
 
         print("== policy_search (docs/policy-search.md acceptance) ==")
         from benchmarks import policy_search
 
         search_rows, search_history = policy_search.acceptance_search()
-        write_fleet_json(rows, smoke=False, phase_breakdown=breakdown,
-                         scenario_rows=scenario_rows,
+        write_fleet_json(rows, smoke=False, scenario_rows=scenario_rows,
                          search_rows=search_rows,
                          search_history=search_history)
 

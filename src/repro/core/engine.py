@@ -32,11 +32,13 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import re
 import warnings
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from . import admission, executor
 from .params import SimParams, load_params
@@ -50,6 +52,21 @@ from .telemetry.record import TraceBuffer, record_step, step_block_rows
 from .telemetry.schema import DEFAULT_TRACE_CAPACITY, RECORD_WIDTH
 from .types import ContainerStatus, PipeStatus
 from .workload import get_workload
+
+# The engine's top-level ``jax.named_scope``s. Every instruction of the
+# compiled loop carries one of them on its ``op_name`` metadata
+# (:func:`scope_map`), so a profiler trace's device ops can be summed
+# per phase; ``loop`` is the while_loop's own work (its cond and the
+# finished-lane masking).
+ENGINE_SCOPES = (
+    "phase1", "faults", "closed_loop", "scheduler", "apply", "advance",
+    "telemetry", "loop",
+)
+UNSCOPED = "unscoped"
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([^\s=%]+)\s*=")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+# a transform's or jit's wrapper around a name-stack entry: vmap(apply)
+_WRAPPED = re.compile(r"^[\w-]+\((.*)\)$")
 
 
 @dataclasses.dataclass
@@ -283,18 +300,18 @@ def _fleet_gated_faults(
     recompute reproduces itself), so the gate is bitwise-neutral. The
     predicate is hoisted to the fleet level because a per-lane cond
     under ``vmap`` lowers to a select that runs both branches."""
-    due = jnp.any(active & (tick >= states.nxt_fault))
+    with jax.named_scope("faults"):
+        due = jnp.any(active & (tick >= states.nxt_fault))
 
-    def apply(sts):
-        with jax.named_scope("faults"):
+        def apply(sts):
             return jax.vmap(
                 lambda s, w, t: executor.apply_faults(s, w, t, params)
             )(sts, wls, tick)
 
-    def skip(sts):
-        return sts, _zero_fault_aux(sts)
+        def skip(sts):
+            return sts, _zero_fault_aux(sts)
 
-    return jax.lax.cond(due, apply, skip, states)
+        return jax.lax.cond(due, apply, skip, states)
 
 
 def _lane_decide(
@@ -310,9 +327,9 @@ def _lane_decide(
 ):
     """One lane, one event, from the scheduler onward (the post-phase-1 /
     post-faults half of the step): schedule, apply the decision, and
-    jump to the lane's next event. The named scopes label the engine
-    phases in XLA/profiler output; they change HLO metadata only, never
-    the computation."""
+    jump to the lane's next event. The named scopes (:data:`ENGINE_SCOPES`)
+    label the engine phases in the compiled HLO's metadata; they never
+    change the computation."""
     if params.closed_loop_active:
         with jax.named_scope("closed_loop"):
             state = admission.apply_closed_loop(state, wl, tick, params)
@@ -336,12 +353,12 @@ def _lane_decide(
                 state, wl, dec, tick, params, early_exit=True
             )
             aux = None
-    acted = (
-        jnp.any(dec.suspend)
-        | jnp.any(dec.reject)
-        | jnp.any(dec.assign_pipe >= 0)
-    )
     with jax.named_scope("advance"):
+        acted = (
+            jnp.any(dec.suspend)
+            | jnp.any(dec.reject)
+            | jnp.any(dec.assign_pipe >= 0)
+        )
         nxt, cursor = _next_event_registers(state, arr_sorted, tick, acted)
         nxt = jnp.minimum(nxt, horizon)
         state = executor.integrate(state, tick, nxt, params, exact_buckets=True)
@@ -477,13 +494,31 @@ def _run_lane_major_engine(
         with jax.named_scope("phase1"):
             return executor.apply_fused_phase1(state, wl, tick, params, ph)
 
+    def tick_kernel(states):
+        with jax.named_scope("phase1"):
+            return fleet_tick(
+                states.ctr_status, states.ctr_end, states.ctr_oom,
+                states.ctr_cpus, states.ctr_ram, states.ctr_pool,
+                states.pipe_status, wls.arrival, states.pipe_release,
+                states.tick, num_pools=params.num_pools, impl=impl,
+            )
+
+    def lanes_active(states):
+        with jax.named_scope("loop"):
+            return states.tick < horizon
+
+    def cond(carry):  # carry[0] is the states, traced or not
+        with jax.named_scope("loop"):
+            return jnp.any(carry[0].tick < horizon)
+
     # finished lanes pass through untouched
-    def keep_fn(active):
-        def keep(n, o):
+    def keep(active, new, old):
+        def one(n, o):
             mask = jnp.reshape(active, (F,) + (1,) * (n.ndim - 1))
             return jnp.where(mask, n, o)
 
-        return keep
+        with jax.named_scope("loop"):
+            return jax.tree.map(one, new, old)
 
     if trace_capacity == 0:
         lane = functools.partial(
@@ -493,21 +528,11 @@ def _run_lane_major_engine(
             _lane_decide, params, horizon, scheduler_fn, False
         )
 
-        def cond(carry):
-            states, _ = carry
-            return jnp.any(states.tick < horizon)
-
         def body(carry):
             states, scheds = carry
             tick = states.tick                     # [F]
-            active = tick < horizon                # [F]
-
-            ph = fleet_tick(
-                states.ctr_status, states.ctr_end, states.ctr_oom,
-                states.ctr_cpus, states.ctr_ram, states.ctr_pool,
-                states.pipe_status, wls.arrival, states.pipe_release,
-                tick, num_pools=params.num_pools, impl=impl,
-            )
+            active = lanes_active(states)          # [F]
+            ph = tick_kernel(states)
 
             if faults_on:
                 # split body: vmap(phase1) -> fleet-gated faults ->
@@ -524,10 +549,7 @@ def _run_lane_major_engine(
                     states, scheds, wls, arr_sorted, tick, ph
                 )
 
-            keep = keep_fn(active)
-            states = jax.tree.map(keep, new_states, states)
-            scheds = jax.tree.map(keep, new_scheds, scheds)
-            return states, scheds
+            return keep(active, (new_states, new_scheds), (states, scheds))
 
         return jax.lax.while_loop(cond, body, (states0, scheds0))
 
@@ -559,21 +581,11 @@ def _run_lane_major_engine(
             )
         return state, sched_state, tbuf
 
-    def cond_t(carry):
-        states, _, _ = carry
-        return jnp.any(states.tick < horizon)
-
     def body_t(carry):
         states, scheds, tbufs = carry
         tick = states.tick
-        active = tick < horizon
-
-        ph = fleet_tick(
-            states.ctr_status, states.ctr_end, states.ctr_oom,
-            states.ctr_cpus, states.ctr_ram, states.ctr_pool,
-            states.pipe_status, wls.arrival, states.pipe_release,
-            tick, num_pools=params.num_pools, impl=impl,
-        )
+        active = lanes_active(states)
+        ph = tick_kernel(states)
 
         if faults_on:
             # same split as the untraced body; the recorder consumes the
@@ -592,13 +604,13 @@ def _run_lane_major_engine(
                 states, scheds, tbufs, wls, arr_sorted, tick, ph, active
             )
 
-        keep = keep_fn(active)
-        states = jax.tree.map(keep, new_states, states)
-        scheds = jax.tree.map(keep, new_scheds, scheds)
+        states, scheds = keep(
+            active, (new_states, new_scheds), (states, scheds)
+        )
         return states, scheds, tbufs
 
     states, scheds, tbufs = jax.lax.while_loop(
-        cond_t, body_t, (states0, scheds0, tbufs0)
+        cond, body_t, (states0, scheds0, tbufs0)
     )
     tbufs = tbufs._replace(records=tbufs.records[:, :trace_capacity])
     return states, scheds, tbufs
@@ -635,6 +647,44 @@ def _fleet_compiled(
     )
 
 
+def _scope_of(op_name: str) -> str:
+    """The first engine scope on an ``op_name`` path, read through the
+    ``jit(...)``/``vmap(...)`` wrappers and the ``while/body``,
+    ``while/cond`` and ``shard_map`` entries around it."""
+    for part in op_name.split("/"):
+        while (m := _WRAPPED.match(part)):
+            part = m.group(1)
+        if part in ENGINE_SCOPES:
+            return part
+    return UNSCOPED
+
+
+def scope_map(hlo_text: str) -> dict[str, str]:
+    """``{instruction name: engine scope}`` of a compiled engine's HLO
+    text (``compiled.as_text()``), every computation's instructions
+    included.
+
+    An instruction's scope is the first of :data:`ENGINE_SCOPES` on its
+    ``op_name``; one with no engine scope, or no metadata at all (the
+    copies XLA inserts), maps to :data:`UNSCOPED`. A profiler trace
+    names each device op by its instruction, so this map splits the
+    trace's device time by engine phase (``sweep.compiled_scope_map``
+    builds it for the program a call ran).
+
+    >>> scope_map('%fusion.3 = f32[4]{0} fusion(f32[4]{0} %p), '
+    ...           'metadata={op_name="jit(_fleet_compiled)/while/body/'
+    ...           'vmap(apply)/gather"}\\n%copy.1 = f32[4]{0} copy(%p)')
+    {'fusion.3': 'apply', 'copy.1': 'unscoped'}
+    """
+    out = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m:
+            op = _OP_NAME.search(line)
+            out[m.group(1)] = _scope_of(op.group(1)) if op else UNSCOPED
+    return out
+
+
 def run(
     paramfile: str | dict | SimParams,
     workload: Workload | None = None,
@@ -658,17 +708,22 @@ def run(
     the simulated state is bitwise-identical either way (guarded by
     tests/test_telemetry.py). On overflow the earliest records win and
     ``result.trace.events_dropped`` counts the rest.
-    """
-    params = load_params(paramfile)
-    engine = engine or params.engine
-    wl = workload if workload is not None else get_workload(params)
-    if params.fault_trace_active and wl.faults is None:
-        # chaos layer on but the workload came in bare (trace replay /
-        # caller-built): materialise the fault trace from params.seed so
-        # both engines replay the identical fault sequence
-        from .faults import attach_fault_trace
 
-        wl = attach_fault_trace(wl, params)
+    While a ``jax.profiler`` trace runs, the host spans
+    ``eudoxia/run/prepare``, ``eudoxia/run/dispatch`` and
+    ``eudoxia/run/unpack`` mark its parts (docs/observability.md).
+    """
+    with TraceAnnotation("eudoxia/run/prepare"):
+        params = load_params(paramfile)
+        engine = engine or params.engine
+        wl = workload if workload is not None else get_workload(params)
+        if params.fault_trace_active and wl.faults is None:
+            # chaos layer on but the workload came in bare (trace replay
+            # / caller-built): materialise the fault trace from
+            # params.seed so both engines replay the same fault sequence
+            from .faults import attach_fault_trace
+
+            wl = attach_fault_trace(wl, params)
     if engine == "python":
         if trace:
             raise ValueError(
@@ -688,21 +743,22 @@ def run(
     capacity = int(trace_capacity) if trace else 0
     if trace and capacity <= 0:
         raise ValueError(f"trace_capacity must be positive, got {trace_capacity}")
-    wls = jax.tree.map(lambda x: x[None], wl)
-    with _quiet_partial_donation():
+    with TraceAnnotation("eudoxia/run/dispatch"), _quiet_partial_donation():
+        wls = jax.tree.map(lambda x: x[None], wl)
         out = _fleet_compiled(
             params, wls, params.scheduling_algo, trace_capacity=capacity
         )
-    events = None
-    if capacity:
-        states, scheds, tbufs = out
-        from .telemetry.decode import decode_lane
+    with TraceAnnotation("eudoxia/run/unpack"):
+        events = None
+        if capacity:
+            states, scheds, tbufs = out
+            from .telemetry.decode import decode_lane
 
-        events = decode_lane(tbufs, 0)
-    else:
-        states, scheds = out
-    state = jax.tree.map(lambda x: x[0], states)
-    sched_state = jax.tree.map(lambda x: x[0], scheds)
+            events = decode_lane(tbufs, 0)
+        else:
+            states, scheds = out
+        state = jax.tree.map(lambda x: x[0], states)
+        sched_state = jax.tree.map(lambda x: x[0], scheds)
     return SimResult(
         state=state, workload=wl, params=params, sched_state=sched_state,
         trace=events,
@@ -710,8 +766,11 @@ def run(
 
 
 __all__ = [
+    "ENGINE_SCOPES",
     "SimResult",
+    "UNSCOPED",
     "run",
+    "scope_map",
     "lane_event_step",
     "lane_event_step_traced",
     "_fleet_compiled",

@@ -35,9 +35,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import shard_map
+from jax.profiler import TraceAnnotation
 
 from . import metrics
-from .engine import _fleet_compiled, _quiet_partial_donation
+from .engine import _fleet_compiled, _quiet_partial_donation, scope_map
 from .params import SimParams
 from .state import INF_TICK, SimState, Workload
 from .types import TICKS_PER_SECOND
@@ -57,8 +58,9 @@ def make_workload_batch(params: SimParams, seeds: Sequence[int]) -> Workload:
     >>> batch.arrival.shape, batch.op_ram.shape
     ((3, 8), (3, 8, 4))
     """
-    keys = jax.vmap(jax.random.PRNGKey)(jnp.asarray(seeds, jnp.uint32))
-    return jax.vmap(lambda k: generate_workload(params, k))(keys)
+    with TraceAnnotation("eudoxia/workload/batch"):
+        keys = jax.vmap(jax.random.PRNGKey)(jnp.asarray(seeds, jnp.uint32))
+        return jax.vmap(lambda k: generate_workload(params, k))(keys)
 
 
 def pad_lanes(wls: Workload, n_lanes: int) -> Workload:
@@ -361,6 +363,11 @@ def fleet_run(
     bitwise-identical to an untraced run, whatever the sharding or
     binning (traces ride the same unbinning permutation as the states).
 
+    While a ``jax.profiler`` trace runs, host spans mark the parts of
+    the call: ``eudoxia/fleet/prepare``, ``/bin``, ``/dispatch``,
+    ``/unbin`` and ``/decode``, the last three only on the paths that
+    run them (docs/observability.md).
+
     >>> from repro.core import SimParams, fleet_run, fleet_summary
     >>> p = SimParams(duration=0.01, max_pipelines=8, max_containers=8,
     ...               max_ops_per_pipeline=4, waiting_ticks_mean=300.0,
@@ -371,6 +378,50 @@ def fleet_run(
     >>> sorted(fleet_summary(states, p))[:2]
     ['admitted_fraction_mean', 'admitted_mean']
     """
+    with TraceAnnotation("eudoxia/fleet/prepare"):
+        scheduler_key, capacity, wls = _prepare_fleet(
+            params, seeds, scheduler_key, workloads, fleet_engine, trace,
+            trace_capacity,
+        )
+        F = wls.arrival.shape[0]
+        n_shards = _resolve_shards(shard, F)
+    if n_shards <= 1:
+        with TraceAnnotation("eudoxia/fleet/dispatch"), _quiet_partial_donation():
+            out = _fleet_compiled(
+                params, wls, scheduler_key, impl, trace_capacity=capacity
+            )
+        if capacity:
+            states, _, tbufs = out
+            with TraceAnnotation("eudoxia/fleet/decode"):
+                return states, _decode_traces(tbufs)
+        states, _ = out
+        return states
+    inv = None
+    if bin_lanes:
+        with TraceAnnotation("eudoxia/fleet/bin"):
+            wls, inv = bin_lanes_by_density(wls, params)
+    F_pad = -(-F // n_shards) * n_shards
+    with TraceAnnotation("eudoxia/fleet/dispatch"), _quiet_partial_donation():
+        out = _fleet_sharded(
+            params, pad_lanes(wls, F_pad), scheduler_key, impl, n_shards,
+            trace_capacity=capacity,
+        )
+    states, tbufs = out if capacity else (out, None)
+    if inv is not None or F_pad != F:
+        with TraceAnnotation("eudoxia/fleet/unbin"):
+            states, tbufs = _unbin(states, tbufs, inv, F)
+    if capacity:
+        with TraceAnnotation("eudoxia/fleet/decode"):
+            return states, _decode_traces(tbufs)
+    return states
+
+
+def _prepare_fleet(
+    params, seeds, scheduler_key, workloads, fleet_engine, trace,
+    trace_capacity,
+):
+    """``fleet_run``'s checks and its lanes: the scheduler key, the
+    static trace capacity (0: telemetry off) and the workload batch."""
     if (seeds is None) == (workloads is None):
         raise ValueError(
             "fleet_run needs exactly one of seeds= (generated lanes) or "
@@ -381,7 +432,7 @@ def fleet_run(
             "fleet_engine is deprecated and ignored unless it names the "
             "removed path: the fused lane-major engine is the only core",
             DeprecationWarning,
-            stacklevel=2,
+            stacklevel=3,
         )
         if fleet_engine != "fused":
             raise ValueError(
@@ -433,45 +484,68 @@ def fleet_run(
         from .faults import attach_fault_traces
 
         wls = attach_fault_traces(wls, params)
-    F = wls.arrival.shape[0]
-    n_shards = _resolve_shards(shard, F)
-    tbufs = None
-    if n_shards <= 1:
-        with _quiet_partial_donation():
-            out = _fleet_compiled(
-                params, wls, scheduler_key, impl, trace_capacity=capacity
-            )
-        if capacity:
-            states, _, tbufs = out
-            return states, _decode_traces(tbufs)
-        states, _ = out
-        return states
-    inv = None
-    if bin_lanes:
-        wls, inv = bin_lanes_by_density(wls, params)
-    F_pad = -(-F // n_shards) * n_shards
-    with _quiet_partial_donation():
-        out = _fleet_sharded(
-            params, pad_lanes(wls, F_pad), scheduler_key, impl, n_shards,
-            trace_capacity=capacity,
-        )
-    states, tbufs = out if capacity else (out, None)
+    return scheduler_key, capacity, wls
+
+
+def _unbin(states, tbufs, inv, F: int):
+    """A sharded call's lanes back in the caller's order, padding
+    dropped; ``inv`` is the binning's inverse permutation, or None when
+    the lanes were not binned."""
     if inv is not None:
         # one gather: unpermute AND strip padding (inv addresses only
         # real lanes; binning put the padding last). Trace buffers join
         # the states in one pytree so they ride the same permutation.
         inv = jnp.asarray(inv)
         if tbufs is not None:
-            states, tbufs = _unbin_states((states, tbufs), inv)
+            return _unbin_states((states, tbufs), inv)
+        return _unbin_states(states, inv), None
+    states = jax.tree.map(lambda x: x[:F], states)
+    if tbufs is not None:
+        tbufs = jax.tree.map(lambda x: x[:F], tbufs)
+    return states, tbufs
+
+
+def compiled_scope_map(
+    params: SimParams,
+    workloads: Workload,
+    scheduler_key: str | None = None,
+    *,
+    shard: str | int | None = None,
+    impl: str = "auto",
+    trace_capacity: int = 0,
+) -> dict[str, str]:
+    """``{instruction name: engine scope}`` (``engine.scope_map``) of
+    the program that ``fleet_run(params, workloads=workloads,
+    scheduler_key=..., shard=..., impl=...)`` runs: ``_fleet_compiled``,
+    or ``_fleet_sharded`` when the call shards. ``run(params)`` runs the
+    unsharded program of a one-lane batch. ``trace_capacity`` is the
+    static capacity the call compiled (0 with telemetry off).
+
+    Only the shapes of ``workloads`` are read (arrays or
+    ``jax.ShapeDtypeStruct``\\ s); the map comes from the same jit with
+    the same static arguments, lowered and compiled ahead of time, which
+    a warm compilation cache answers without compiling.
+    """
+    scheduler_key = scheduler_key or params.scheduling_algo
+    F = workloads.arrival.shape[0]
+    n_shards = _resolve_shards(shard, F)
+    F_pad = -(-F // n_shards) * n_shards
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((F_pad,) + x.shape[1:], x.dtype),
+        workloads,
+    )
+    with _quiet_partial_donation():
+        if n_shards <= 1:
+            lowered = _fleet_compiled.lower(
+                params, shapes, scheduler_key, impl,
+                trace_capacity=trace_capacity,
+            )
         else:
-            states = _unbin_states(states, inv)
-    elif F_pad != F:
-        states = jax.tree.map(lambda x: x[:F], states)
-        if tbufs is not None:
-            tbufs = jax.tree.map(lambda x: x[:F], tbufs)
-    if capacity:
-        return states, _decode_traces(tbufs)
-    return states
+            lowered = _fleet_sharded.lower(
+                params, shapes, scheduler_key, impl, n_shards,
+                trace_capacity=trace_capacity,
+            )
+    return scope_map(lowered.compile().as_text())
 
 
 def _decode_traces(tbufs):
@@ -572,6 +646,7 @@ def _fleet_hit_rate(states: SimState) -> float:
 
 __all__ = [
     "attach_policies",
+    "compiled_scope_map",
     "fleet_run",
     "fleet_summary",
     "make_workload_batch",

@@ -50,6 +50,7 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .params import SimParams
 from .state import INF_TICK, Workload
@@ -65,7 +66,13 @@ def _quantize_gb(x: jax.Array) -> jax.Array:
 
 
 def generate_workload(params: SimParams, key: jax.Array | None = None) -> Workload:
-    """Vectorised random workload table."""
+    """Vectorised random workload table (host span
+    ``eudoxia/workload/generate`` while a profiler trace runs)."""
+    with TraceAnnotation("eudoxia/workload/generate"):
+        return _draw_workload(params, key)
+
+
+def _draw_workload(params: SimParams, key: jax.Array | None) -> Workload:
     if key is None:
         key = jax.random.PRNGKey(params.seed)
     MP, MO = params.max_pipelines, params.max_ops_per_pipeline

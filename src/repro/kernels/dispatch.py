@@ -5,8 +5,8 @@ follow the same convention: a Pallas VMEM kernel, a bitwise-equivalent
 jnp reference, and an ``impl="auto"`` wrapper that picks the kernel on
 TPU (for shapes the kernel tiles — explicit lane-major batches) and
 the reference everywhere else. This module is the one place that rule
-lives, so benchmarks can report which implementation a given run
-resolved to (BENCH_fleet.json ``phase_breakdown.impl``).
+lives, so a run can report which implementation it resolved to
+(``resolved_impl``; ``chip_smoke.py`` prints it).
 """
 from __future__ import annotations
 
