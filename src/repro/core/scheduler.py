@@ -112,6 +112,23 @@ def onehot_add(arr: jax.Array, idx: jax.Array, val):
     return jnp.where(iota == idx, arr + val, arr)
 
 
+def onehot_take(arr: jax.Array, idx: jax.Array) -> jax.Array:
+    """``arr[idx]`` for a 1-D ``arr`` of 32-bit or bool values and
+    in-range ``idx``, as a one-hot masked reduction.
+
+    Under the engine's per-lane ``vmap`` a read at a traced index is a
+    batched ``gather``, which a TPU v5e runs one output element at a
+    time (~8.8 ns each); this stays compare, select and reduce. Exact
+    for every value: one position matches, and the sum is of its int32
+    bits (``any`` for bools). An out-of-range ``idx`` reads 0."""
+    oh = idx[..., None] == jnp.arange(arr.shape[0], dtype=jnp.int32)
+    if arr.dtype == jnp.bool_:
+        return jnp.any(oh & arr, axis=-1)
+    bits = jax.lax.bitcast_convert_type(arr, jnp.int32)
+    s = jnp.sum(jnp.where(oh, bits, 0), axis=-1, dtype=jnp.int32)
+    return jax.lax.bitcast_convert_type(s, arr.dtype)
+
+
 # ---------------------------------------------------------------------------
 # Decision-slot loop runner shared by the K-assignment schedulers.
 # ---------------------------------------------------------------------------

@@ -50,10 +50,11 @@ def assign_gather(
     *, max_containers: int, max_pipelines: int, impl: str = "auto",
     interpret: bool = False,
 ):
-    """Fused decision landing: scatter the collected assignment rows
-    onto the container/pipeline axes as one batched masked pass (see
-    ``ref.assign_gather_ref`` for the bitwise contract vs the
-    per-slot ``lax.cond`` commits of ``apply_decision``)."""
+    """Fused decision landing: land the collected assignment rows on
+    the container/pipeline axes as one-hot reductions over the rows,
+    with no gather (see ``ref.assign_gather_ref`` for the bitwise
+    contract vs the per-slot ``lax.cond`` commits of
+    ``apply_decision``)."""
     if use_pallas(impl, batched=valid.ndim == 2):
         return assign_gather_kernel(
             valid, slot, pipe, pool, cpus, ram, end, oom, prio, warm,

@@ -18,21 +18,36 @@ ENTIRE SimState once per slot. This module fuses both landings:
   slot.
 
 The reference computes the landings as masked one-hot reductions and
-gathers over ``[MC, MP]`` — NOT the seed's ``.at[idx].add/max/set``
-scatters. On XLA:CPU a dynamic-index scatter under the engine's
-per-lane ``vmap`` lowers to a serialized ``while`` thunk per scatter
-(~180us fixed cost each), so the scatter form is the slow one there;
-the one-hot form lowers to elementwise ops + reduces and is also the
-regular tiling form the Pallas kernel / MXU wants, so ref and kernel
-share one shape. The one-hot forms are bitwise identical to the seed's
-scatters:
+selects over ``[MC, MP]`` — NOT the seed's ``.at[idx].add/max/set``
+scatters, and NOT dynamic-index gathers. Both dynamic forms are slow
+under the engine's per-lane ``vmap``, each on its own backend:
+
+* on XLA:CPU a dynamic-index scatter lowers to a serialized ``while``
+  thunk per scatter (~180us fixed cost each);
+* on a TPU v5e a batched ``gather`` runs one output element after
+  another, ~8.8 ns each: the landing's two gathers of ``[64, 16]``
+  grant rows onto ``[64, 2048]`` pipeline tables took 1,153 us per
+  loop trip each, 69% of the ``mc_fleet`` benchmark cell's device time.
+
+Both landings reduce a one-hot membership: the retirement landing a
+``[MC, MP]`` one over containers, the decision landing a ``[K, MC]`` /
+``[K, MP]`` one over its ``K`` rows (``K`` is static), with the rows
+entering whole as ``[K]`` vectors. Each lowers to fused compare, select
+and reduce, with no gather. (A select-fold over the rows, reading row
+``k`` at a static index, gathered nothing either; but at one lane XLA
+made each row value a scalar operand of the table-wide fusion, ~35 per
+landed field, and on a TPU v5e each such fusion took ~11.5 us per loop
+trip.) The reductions are bitwise identical to the seed's scatters:
 
 * int/bool scatters with unique indices == one-hot masked reductions,
   exactly;
-* the f32 landings (``cpus``/``ram``/latency terms) have at most one
-  nonzero term per output element, and ``x + 0.0 == x`` bitwise for
-  every ``x != -0.0`` (allocations and latencies are never ``-0.0``),
-  so the kernel's one-hot sums are fp-exact too;
+* the decision landing sums the int32 *bits* of its values, with at
+  most one row per output element, so it is exact for every dtype and
+  value, ``-0.0`` included;
+* the retirement landing's f32 latency terms (and the Pallas kernels'
+  one-hot sums) have at most one nonzero term per output element, and
+  ``x + 0.0 == x`` bitwise for every ``x != -0.0`` (allocations and
+  latencies are never ``-0.0``), so those sums are fp-exact too;
 * order-sensitive f32 accumulators (pool frees, cache bytes) are NOT
   landed here — the executor carries them sequentially, preserving the
   seed's left-fold association.
@@ -141,48 +156,49 @@ def retire_land_ref(
               prio, tick)
 
 
+def _onehot_land(idx, n: int, fields):
+    """Land ``fields[f][k]`` at position ``idx[k]`` of ``n`` outputs.
+
+    A masked one-hot reduction over the ``K`` rows: ``hit`` marks the
+    positions some row names; ``outs[f]`` holds that row's value there
+    and zero elsewhere. With at most one row per position the int32 sum
+    of the values' bits is exact for every dtype and value (``-0.0``
+    and NaN payloads included). The rows enter whole, as ``[K]``
+    vectors: no read at a traced index (under ``vmap`` a batched
+    ``gather``) and no row sliced out to a scalar operand, and XLA fuses
+    compare, select and reduce into one pass per field.
+    """
+    m = idx[:, None] == jnp.arange(n, dtype=idx.dtype)[None, :]  # [K, n]
+    outs = []
+    for x in fields:
+        if x.dtype == jnp.bool_:
+            outs.append(jnp.any(m & x[:, None], axis=0))
+        else:
+            bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+            s = jnp.sum(jnp.where(m, bits[:, None], 0), axis=0,
+                        dtype=jnp.int32)
+            outs.append(jax.lax.bitcast_convert_type(s, x.dtype))
+    return jnp.any(m, axis=0), outs
+
+
 def _assign_gather_1d(
     valid, slot, pipe, pool, cpus, ram, end, oom, prio, warm, timed,
     max_containers: int, max_pipelines: int,
 ):
-    i32 = jnp.int32
+    i32, f32 = jnp.int32, jnp.float32
     # valid rows carry unique slots/pipes (the loop consumes each empty
     # slot and waiting pipeline it assigns), so every output element has
-    # at most one contributing row and the masked one-hot reductions are
-    # exact (single-term sums; ``x + 0.0 == x`` bitwise for the f32
-    # values, which are never ``-0.0``). One-hot instead of scatter
-    # because batched scatters serialize on XLA:CPU under the engine's
-    # per-lane vmap; these reduce to elementwise ops + reduces.
+    # at most one contributing row, whose bits land unchanged.
+    # Invalid rows point one past the end and land nowhere.
     sv = jnp.where(valid, slot, max_containers)
     pv = jnp.where(valid, pipe, max_pipelines)
-
-    # one one-hot membership test per axis, then *gathers*: with unique
-    # row indices, ``argmax`` over the one-hot recovers the (single)
-    # contributing row per output element, and each landed field is one
-    # [K]-to-[MC] gather instead of a full masked reduction per field
-    oh_c = sv[:, None] == jnp.arange(max_containers, dtype=i32)[None, :]
-    hit_c = jnp.any(oh_c, axis=0)
-    rr_c = jnp.argmax(oh_c, axis=0)
-
-    def land_c(x, dtype=i32):
-        return jnp.where(hit_c, x.astype(dtype)[rr_c], dtype(0))
-
-    l_pipe = land_c(pipe)
-    l_pool = land_c(pool)
-    l_cpus = land_c(cpus, jnp.float32)
-    l_ram = land_c(ram, jnp.float32)
-    l_end = land_c(end)
-    l_oom = land_c(oom)
-    l_prio = land_c(prio)
-    l_warm = hit_c & warm[rr_c]
-    l_timed = hit_c & timed[rr_c]
-
-    oh_p = pv[:, None] == jnp.arange(max_pipelines, dtype=i32)[None, :]
-    hit_p = jnp.any(oh_p, axis=0)
-    rr_p = jnp.argmax(oh_p, axis=0)
-    l_pcpus = jnp.where(hit_p, cpus[rr_p], jnp.float32(0))
-    l_pram = jnp.where(hit_p, ram[rr_p], jnp.float32(0))
-
+    hit_c, (l_pipe, l_pool, l_cpus, l_ram, l_end, l_oom, l_prio, l_warm,
+            l_timed) = _onehot_land(sv, max_containers, (
+                pipe.astype(i32), pool.astype(i32), cpus.astype(f32),
+                ram.astype(f32), end.astype(i32), oom.astype(i32),
+                prio.astype(i32), warm.astype(bool), timed.astype(bool)))
+    hit_p, (l_pcpus, l_pram) = _onehot_land(
+        pv, max_pipelines, (cpus.astype(f32), ram.astype(f32)))
     return (
         hit_c, l_pipe, l_pool, l_cpus, l_ram, l_end, l_oom, l_prio,
         l_warm, l_timed, hit_p, l_pcpus, l_pram,
@@ -201,7 +217,9 @@ def assign_gather_ref(
     Rows (``[.., K]``) come from the executor's early-exit loop; valid
     rows carry unique ``slot``/``pipe`` indices (the loop consumes each
     empty slot and waiting pipeline it assigns), so every output element
-    has at most one contributing row.
+    has at most one contributing row. Each axis is a one-hot reduction
+    over the ``K`` rows (:func:`_onehot_land`): no gather, exact for
+    every dtype and value.
 
     Returns ``(hit_c, l_pipe, l_pool, l_cpus, l_ram, l_end, l_oom,
     l_prio, l_warm, l_timed, hit_p, l_pcpus, l_pram)``: the container-

@@ -3,7 +3,7 @@
 ``kernels/state_update`` replaces the executor's write-side scatters:
 ``retire_land`` fuses the ``.at[pid].add/max`` retirement landings of
 ``_apply_retirements``, and ``assign_gather`` lands the assignment rows
-collected by ``apply_decision``'s early-exit loop as one masked scatter
+collected by ``apply_decision``'s early-exit loop in one one-hot pass
 instead of a full-state ``lax.cond`` per slot. The sequential passes
 stay exported as the oracles; everything here pins the fused paths to
 them bitwise — including the corners the issue calls out (capacity
@@ -12,15 +12,17 @@ retire + release + arrival) — and checks the Pallas kernels against
 the jnp references in interpret mode so CPU CI covers them.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import SimParams, generate_workload
 from repro.core import executor
-from repro.core.scheduler import SchedDecision
+from repro.core.scheduler import SchedDecision, onehot_take
 from repro.core.state import INF_TICK, init_state
 from repro.core.types import ContainerStatus, PipeStatus, TICKS_PER_SECOND
 from repro.kernels.state_update import (
@@ -432,13 +434,10 @@ def test_retire_kernel_matches_ref(seed, F, timeout_on):
         )
 
 
-@settings(max_examples=10, deadline=None)
-@given(seed=st.integers(0, 1000), F=st.sampled_from([1, 4, 6]))
-def test_assign_kernel_matches_ref(seed, F):
-    rng = _rng(seed)
-    K, MC, MP = 8, 16, 32
-    # valid rows carry unique slots/pipes per lane (the loop invariant)
-    valid = jnp.asarray(rng.random((F, K)) < 0.6)
+def _draw_assign_rows(rng, F, K, MC, MP, p_valid=0.6):
+    """Per-lane assignment rows: valid rows carry unique slots/pipes
+    (the loop invariant)."""
+    valid = jnp.asarray(rng.random((F, K)) < p_valid)
     slot = jnp.stack([
         jnp.asarray(rng.permutation(MC)[:K], jnp.int32) for _ in range(F)
     ])
@@ -453,7 +452,14 @@ def test_assign_kernel_matches_ref(seed, F):
     prio = jnp.asarray(rng.integers(0, 3, (F, K)), jnp.int32)
     warm = jnp.asarray(rng.random((F, K)) < 0.5)
     timed = jnp.asarray(rng.random((F, K)) < 0.3)
-    args = (valid, slot, pipe, pool, cpus, ram, end, oom, prio, warm, timed)
+    return (valid, slot, pipe, pool, cpus, ram, end, oom, prio, warm, timed)
+
+
+@settings(max_examples=10, deadline=None)
+@given(seed=st.integers(0, 1000), F=st.sampled_from([1, 4, 6]))
+def test_assign_kernel_matches_ref(seed, F):
+    K, MC, MP = 8, 16, 32
+    args = _draw_assign_rows(_rng(seed), F, K, MC, MP)
     ref = assign_gather_ref(*args, max_containers=MC, max_pipelines=MP)
     out = assign_gather_kernel(
         *args, max_containers=MC, max_pipelines=MP, block_fleet=4,
@@ -463,6 +469,137 @@ def test_assign_kernel_matches_ref(seed, F):
         np.testing.assert_array_equal(
             np.asarray(r), np.asarray(o), err_msg=f"output {i}"
         )
+
+
+# ---------------------------------------------------------------------------
+# assign_gather_ref vs a plain ``.at[].set`` scatter, at the shapes of the
+# benchmark's fleet cell: 64 lanes, K = 16 rows, 64 containers, 2048
+# pipelines.
+# ---------------------------------------------------------------------------
+FLEET_F, FLEET_K, FLEET_MC, FLEET_MP = 64, 16, 64, 2048
+ASSIGN_OUT = (
+    "hit_c l_pipe l_pool l_cpus l_ram l_end l_oom l_prio l_warm l_timed"
+    " hit_p l_pcpus l_pram".split()
+)
+
+
+@functools.partial(jax.jit, static_argnames=("MC", "MP"))
+def _assign_scatter_oracle(
+    valid, slot, pipe, pool, cpus, ram, end, oom, prio, warm, timed, MC, MP
+):
+    def lane(valid, slot, pipe, pool, cpus, ram, end, oom, prio, warm,
+             timed):
+        # invalid rows point past the end and are dropped
+        sv = jnp.where(valid, slot, MC)
+        pv = jnp.where(valid, pipe, MP)
+
+        def on(idx, n, x):
+            return jnp.zeros((n,), x.dtype).at[idx].set(x, mode="drop")
+
+        c = [on(sv, MC, x) for x in (
+            pipe, pool, cpus, ram, end, oom, prio, warm, timed)]
+        return (
+            on(sv, MC, valid), *c,
+            on(pv, MP, valid), on(pv, MP, cpus), on(pv, MP, ram),
+        )
+
+    return jax.vmap(lane)(
+        valid, slot, pipe, pool, cpus, ram, end, oom, prio, warm, timed
+    )
+
+
+def _fleet_assign_case(case):
+    F, K, MC, MP = FLEET_F, FLEET_K, FLEET_MC, FLEET_MP
+    rng = _rng(14)
+    p_valid = {"random": 0.6, "all_invalid": 0.0, "all_valid": 1.0,
+               "extremes": 0.8}[case]
+    rows = list(_draw_assign_rows(rng, F, K, MC, MP, p_valid))
+    if case == "extremes":
+        f32 = np.finfo(np.float32)
+        floats = np.array(
+            [-0.0, 0.0, f32.max, -f32.max, f32.tiny, f32.smallest_subnormal,
+             np.inf, -np.inf, 1.0], np.float32,
+        )
+        ints = np.array(
+            [np.iinfo(np.int32).min, np.iinfo(np.int32).max, -1, 0],
+            np.int32,
+        )
+        for i in (4, 5):  # cpus, ram
+            rows[i] = jnp.asarray(rng.choice(floats, (F, K)))
+        for i in (6, 7):  # end, oom
+            rows[i] = jnp.asarray(rng.choice(ints, (F, K)))
+        # bools on every row, both values in every lane
+        rows[9] = jnp.asarray(np.arange(F * K).reshape(F, K) % 2 == 0)
+        rows[10] = ~rows[9]
+    return tuple(rows)
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint8)
+
+
+@pytest.mark.parametrize(
+    "case", ["random", "all_invalid", "all_valid", "extremes"]
+)
+def test_assign_land_matches_scatter_oracle(case):
+    F, MC, MP = FLEET_F, FLEET_MC, FLEET_MP
+    args = _fleet_assign_case(case)
+    want = _assign_scatter_oracle(*args, MC=MC, MP=MP)
+    got = assign_gather_ref(*args, max_containers=MC, max_pipelines=MP)
+    for name, w, g in zip(ASSIGN_OUT, want, got):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        # bitwise: -0.0 must land as -0.0, not as 0.0
+        np.testing.assert_array_equal(_bits(g), _bits(w), err_msg=name)
+    n_hit = int(np.asarray(got[0]).sum())
+    if case == "all_invalid":
+        assert n_hit == 0
+    elif case == "all_valid":
+        assert n_hit == F * FLEET_K
+    else:
+        assert 0 < n_hit < F * FLEET_K
+
+
+def test_assign_land_lowers_without_gather():
+    """The vmapped landing at the fleet cell's shapes compiles with no
+    ``gather``: under ``vmap`` a read at a traced index is a batched
+    gather, which a TPU runs one output element at a time."""
+    F, K, MC, MP = FLEET_F, FLEET_K, FLEET_MC, FLEET_MP
+    args = _fleet_assign_case("random")
+    hlo = assign_gather_ref.lower(
+        *args, max_containers=MC, max_pipelines=MP
+    ).compile().as_text()
+    assert not re.search(r"\bgather\(", hlo)
+    # the check sees the op it looks for: a vmapped dynamic read is one
+    probe = jax.jit(jax.vmap(lambda x, i: x[i])).lower(
+        jnp.zeros((F, MP), jnp.float32), jnp.zeros((F, K), jnp.int32)
+    ).compile().as_text()
+    assert re.search(r"\bgather\(", probe)
+
+
+@pytest.mark.parametrize("dtype", ["bool", "int32", "float32"])
+def test_onehot_take_matches_indexing(dtype):
+    """``onehot_take`` reads the executor's K rows per lane bitwise like
+    ``x[idx]``, and the vmapped read lowers with no ``gather``."""
+    F, K, MP = FLEET_F, FLEET_K, FLEET_MP
+    rng = _rng(15)
+    if dtype == "bool":
+        x = rng.random((F, MP)) < 0.5
+    elif dtype == "int32":
+        x = rng.integers(np.iinfo(np.int32).min, np.iinfo(np.int32).max,
+                         (F, MP), dtype=np.int32)
+    else:
+        f32 = np.finfo(np.float32)
+        x = rng.choice(np.array(
+            [-0.0, 0.0, f32.max, -f32.max, f32.smallest_subnormal, np.inf,
+             -np.inf, 0.5], np.float32), (F, MP))
+    x = jnp.asarray(x)
+    idx = jnp.asarray(rng.integers(0, MP, (F, K)), jnp.int32)
+    take = jax.jit(jax.vmap(onehot_take))
+    np.testing.assert_array_equal(
+        _bits(take(x, idx)), _bits(jax.vmap(lambda a, i: a[i])(x, idx))
+    )
+    hlo = take.lower(x, idx).compile().as_text()
+    assert not re.search(r"\bgather\(", hlo)
 
 
 def test_dispatch_kernel_impl_matches_ref():
