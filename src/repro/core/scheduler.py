@@ -563,8 +563,11 @@ def _policy_family(early_exit: bool, static_policy: PolicyParams | None):
             # fits test — the grant is the full pool anyway
             do_excl = valid & idle0 & ~assigned
             do = jnp.where(excl_on, do_excl, do_norm)
-            use_pool = jnp.where(fits, pool, pool2)
-            commit_victim = has_victim & ~fits & fits2
+            # exclusive mode never preempts: its one grant lands on the
+            # chosen pool, and a grant that does not fit waits for an
+            # idle cluster instead of suspending a victim
+            use_pool = jnp.where(fits | excl_on, pool, pool2)
+            commit_victim = has_victim & ~fits & fits2 & ~excl_on
             suspend = jnp.where(
                 commit_victim,
                 onehot_set(dec.suspend, victim_c, True),

@@ -11,6 +11,11 @@ Donation contract: ``fleet_run`` consumes its workload batch, so the
 caller passes a ``make_scenarios`` *factory* that rebuilds the batch
 (bitwise, from fixed seeds) on every call; arrival tables are copied to
 host before the engine sees them.
+
+While a ``jax.profiler`` trace runs, host spans mark the call's own
+parts around ``fleet_run``'s: ``eudoxia/search/grid`` (the tiling and
+the host copy of the arrivals) and ``eudoxia/search/objectives`` (the
+per-lane statistics and their reduction per candidate).
 """
 from __future__ import annotations
 
@@ -18,6 +23,7 @@ from typing import Callable, Sequence
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.metrics import fleet_lane_stats
 from repro.core.params import SimParams
@@ -68,9 +74,11 @@ def evaluate_policies(
     low-fidelity prefixes of the same batch).
 
     Returns ``{"objectives": [C, 4], "per_candidate": {stat: [C]},
-    "C": C, "S": S}`` with objective columns :data:`OBJECTIVES`;
-    candidates whose every lane finished nothing get NaN latency
-    objectives (never an exception).
+    "C": C, "S": S, "states": SimState}`` with objective columns
+    :data:`OBJECTIVES` and the final lane states of the grid, lane
+    ``c*S + s`` scenario ``s`` under candidate ``c``; candidates whose
+    every lane finished nothing get NaN latency objectives (never an
+    exception).
     """
     wls, params = make_scenarios()
     if wls.policy is not None:
@@ -82,30 +90,32 @@ def evaluate_policies(
         if lane_limit <= 0:
             raise ValueError(f"lane_limit must be positive, got {lane_limit}")
         wls = jax.tree.map(lambda x: x[:lane_limit], wls)
-    grid, C, S = policy_grid_workloads(wls, policies)
-    # host copies BEFORE the engine donates (consumes) the batch
-    arrival = np.asarray(grid.arrival)
+    with TraceAnnotation("eudoxia/search/grid"):
+        grid, C, S = policy_grid_workloads(wls, policies)
+        # host copies BEFORE the engine donates (consumes) the batch
+        arrival = np.asarray(grid.arrival)
     states = fleet_run(
         params.replace(scheduling_algo="policy"),
         workloads=grid,
         shard=shard,
     )
-    lanes = fleet_lane_stats(states, params, arrival=arrival)
-
-    per_candidate = {
-        name: _nanmean_cols(
-            np.asarray(col, np.float64).reshape(C, S)
+    with TraceAnnotation("eudoxia/search/objectives"):
+        lanes = fleet_lane_stats(states, params, arrival=arrival)
+        per_candidate = {
+            name: _nanmean_cols(
+                np.asarray(col, np.float64).reshape(C, S)
+            )
+            for name, col in lanes.items()
+        }
+        objectives = np.stack(
+            [per_candidate[name] for name in OBJECTIVES], axis=1
         )
-        for name, col in lanes.items()
-    }
-    objectives = np.stack(
-        [per_candidate[name] for name in OBJECTIVES], axis=1
-    )
     return {
         "objectives": objectives,
         "per_candidate": per_candidate,
         "C": C,
         "S": S,
+        "states": states,
     }
 
 

@@ -207,3 +207,45 @@ def test_program_spans_nest_in_order(call, want, tmp_path):
         assert _inside(by["eudoxia/workload/batch"], prepare)
         assert _inside(by["eudoxia/workload/generate"],
                        by["eudoxia/workload/batch"])
+
+
+def test_policy_search_spans_and_states(tmp_path):
+    """``evaluate_policies`` marks its grid and its objectives with host
+    spans around ``fleet_run``'s, and returns the grid's final states
+    bitwise as ``fleet_run`` gives them on the same grid."""
+    import numpy as np
+
+    from repro.core.policy import DEFAULT_POINTS
+    from repro.core.sweep import policy_grid_workloads
+    from repro.search.grid import evaluate_policies
+
+    p = SimParams(**TINY, scheduling_algo="policy")
+    pols = np.stack([DEFAULT_POINTS[n].to_vector()
+                     for n in ("naive", "priority", "sjf")])
+
+    def make():
+        return make_workload_batch(p, [0, 1]), p
+
+    res = {}
+
+    def search():
+        res["r"] = evaluate_policies(make, pols)
+        return res["r"]["states"]
+
+    spans = _program_spans(search, tmp_path)
+    names = [n for _, _, n in spans if n.startswith("eudoxia/")
+             and not n.startswith("eudoxia/workload/")]
+    assert names == ["eudoxia/search/grid", "eudoxia/fleet/prepare",
+                     "eudoxia/fleet/dispatch", "eudoxia/search/objectives"]
+    by = {n: (s, e) for s, e, n in spans}
+    for a, b in zip(names, names[1:]):
+        assert by[a][1] <= by[b][0], (a, b)  # one after the other
+
+    grid, C, S = policy_grid_workloads(make_workload_batch(p, [0, 1]), pols)
+    want = fleet_run(p, workloads=grid)
+    got = res["r"]["states"]
+    assert got.done_count.shape == (C * S,)
+    for f in want._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(got, f)),
+                                      np.asarray(getattr(want, f)),
+                                      err_msg=f)

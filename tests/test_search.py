@@ -18,6 +18,8 @@ Plus the cross-engine parity leg: the Python reference engine accepts
 ``PolicyParams`` vectors through the same dynamic ``"policy"`` key and
 matches the fused engine's states on them.
 """
+import functools
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -296,27 +298,53 @@ PARITY_FIELDS = [
 ]
 
 
-@pytest.mark.parametrize(
-    "point",
-    [
-        DEFAULT_POINTS["priority_pool"],
-        DEFAULT_POINTS["sjf"],
-        # an off-grid point no named scheduler maps to
-        PolicyParams(
-            chunk_frac=0.2,
-            size_weight=0.5,
-            prio_weight=1.0,
-            preempt=1.0,
-            multi_pool=1.0,
-            cache_pin=1.0,
-        ),
-    ],
-    ids=["priority_pool", "sjf", "searched"],
-)
+# random points of the search box (drawn in the test, off collection):
+# about a quarter are exclusive with preemption on, the mode whose
+# grant must neither preempt nor move pools
+N_RANDOM_POINTS = 40
+# naive mode with preemption switched on: exclusive grants of whole
+# pools meet running lower-priority containers in the arena below
+EXCLUSIVE_PREEMPT = DEFAULT_POINTS["naive"]._replace(preempt=1.0)
+PARITY_POINTS = {
+    "priority_pool": DEFAULT_POINTS["priority_pool"],
+    "sjf": DEFAULT_POINTS["sjf"],
+    # an off-grid point no named scheduler maps to
+    "searched": PolicyParams(
+        chunk_frac=0.2,
+        size_weight=0.5,
+        prio_weight=1.0,
+        preempt=1.0,
+        multi_pool=1.0,
+        cache_pin=1.0,
+    ),
+    "exclusive_preempt": EXCLUSIVE_PREEMPT,
+    "exclusive_preempt_multi_pool": EXCLUSIVE_PREEMPT._replace(
+        multi_pool=1.0
+    ),
+    **{f"uniform{i:02d}": i for i in range(N_RANDOM_POINTS)},
+}
+
+
+@functools.lru_cache(maxsize=1)
+def _uniform_points() -> np.ndarray:
+    import jax
+
+    space = PolicySpace()
+    return space.denormalize(
+        space.sample_uniform(jax.random.PRNGKey(0), N_RANDOM_POINTS)
+    )
+
+
+@pytest.mark.parametrize("point", PARITY_POINTS.values(),
+                         ids=PARITY_POINTS.keys())
 def test_python_engine_policy_parity(point):
-    params = _arena().replace(max_pipelines=24)
+    # busy enough that arrivals meet running containers of every
+    # priority, so exclusive lanes face a preemption they must not make
+    params = _arena().replace(max_pipelines=32, duration=0.1)
+    vec = (_uniform_points()[point] if isinstance(point, int)
+           else point.to_vector())
     wl = generate_workload(params)
-    wl = wl._replace(policy=point.to_vector())
+    wl = wl._replace(policy=vec)
     fused = run(params, workload=wl, engine="event")
     ref = run(params, workload=wl, engine="python")
     assert int(np.asarray(fused.state.done_count)) > 0  # non-trivial sim
